@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from benchmarks import common as C
 from repro.core.repository import Repository
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 K = 8
 D = 100           # deliberately not a multiple of 8*128
@@ -159,6 +160,10 @@ def run(rows: C.Rows):
     # mesh-sharded engine: the fake device count must be set before jax
     # initializes, so the measurement runs in a subprocess and its rows are
     # merged here (same CSV contract -> same BENCH_kernels.json entries)
+    if jax.default_backend() == "tpu":
+        print("fuse_e2e: mesh row skipped: it measures a forced host-device "
+              "CPU mesh, and this process holds the TPU", file=sys.stderr)
+        return
     for line in _mesh_bench_subprocess(8):
         name, us, derived = line.split(",", 2)
         rows.add(name, float(us), derived)
@@ -175,7 +180,18 @@ def _force_device_env(n_devices: int) -> dict:
     return env
 
 
+def _require_cpu_backend() -> None:
+    """The mesh row is a CPU fake-device measurement by construction: on a
+    TPU host this process holds the chip, so a JAX child would fail or
+    hang reaching for it, and forced host devices are no TPU mesh."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "fuse_e2e's mesh row measures a forced host-device CPU mesh; "
+            "it does not run on a TPU backend")
+
+
 def _mesh_bench_subprocess(n_devices: int):
+    _require_cpu_backend()
     env = _force_device_env(n_devices)
     env["PYTHONPATH"] = "src" + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
@@ -198,7 +214,7 @@ def _mesh_main(n_devices: int) -> None:
     assert jax.device_count() == n_devices, (
         f"expected {n_devices} devices, got {jax.device_count()} — "
         "set XLA_FLAGS=--xla_force_host_platform_device_count before jax init")
-    mesh = jax.make_mesh((n_devices,), ("model",))
+    mesh = make_mesh((n_devices,), ("model",))
     base = _model(jax.random.PRNGKey(0))
     contribs = _contributions(base, K)
     n_params = sum(x.size for x in jax.tree.leaves(base))
@@ -228,6 +244,7 @@ def main() -> None:
         rows.emit()
         return
     if args.mesh:
+        _require_cpu_backend()
         if (jax.device_count() != args.mesh
                 and os.environ.get("_REPRO_MESH_REEXEC") != "1"):
             # direct CLI use without the flag: re-exec ONCE with it set (the

@@ -65,6 +65,7 @@ from repro.checkpoint import io as ckpt
 from repro.configs import get_config, reduce_config
 from repro.core.repository import Repository
 from repro.launch import host_tuning
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import init_lm
 from repro.serve.cold_service import AdmissionPolicy, ColdService, ContributorClient
 from repro.serve.engine import Engine
@@ -104,7 +105,7 @@ def harness(*, arch: str = "gemma3-1b", rounds: int = 4, clients: int = 2,
                 f"--mesh {mesh} needs {mesh} devices, have "
                 f"{jax.device_count()} (set XLA_FLAGS="
                 f"--xla_force_host_platform_device_count={mesh})")
-        kw["mesh"] = jax.make_mesh((mesh,), ("model",))
+        kw["mesh"] = make_mesh((mesh,), ("model",))
     tmp = None
     if root is None:
         tmp = tempfile.TemporaryDirectory(prefix="serve_load_")

@@ -25,6 +25,12 @@ With ``--mesh N`` the daemon opens the repository on an N-device mesh
 (the driver forces the fake host-device count for that child); the
 contributors are unchanged — the queue format is engine-agnostic.
 
+Only the daemon holds an accelerator: it inherits the caller's
+environment, while the driver and every contributor run with
+``JAX_PLATFORMS=cpu`` (their JAX work is host-side pytree handling and
+the closed-form checks).  A chip belongs to one process, so on a TPU host
+the daemon is the one process that can fuse on it.
+
 ``--duplicates D`` additionally launches D *shadow* contributors, each
 replaying contributor 0's exact submission every round under its own
 name, and arms the daemon's content-based novelty screen
@@ -260,6 +266,15 @@ def _routed_checks(args, root, st, elapsed) -> int:
 
 
 def driver_main(args) -> int:
+    daemon_env = dict(os.environ)
+    daemon_env["PYTHONPATH"] = "src" + (
+        os.pathsep + daemon_env["PYTHONPATH"]
+        if daemon_env.get("PYTHONPATH") else "")
+    # the daemon is the one process that may hold an accelerator; this
+    # driver and the contributors stay on the CPU
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    env = dict(daemon_env, JAX_PLATFORMS="cpu")
+
     from repro.checkpoint import io as ckpt
     from repro.serve.cold_service import ContributorClient
 
@@ -269,10 +284,6 @@ def driver_main(args) -> int:
     ckpt.save(base_npz, {"w": np.zeros((W,), np.float32),
                          "b": np.zeros((B,), np.float32)})
 
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    daemon_env = dict(env)
     if args.mesh:
         flags = daemon_env.get("XLA_FLAGS", "")
         daemon_env["XLA_FLAGS"] = (flags + " " if flags else "") + \

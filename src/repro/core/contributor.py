@@ -34,6 +34,8 @@ class Contributor:
     # Repository's fusion_op="fisher"; Matena & Raffel 2021, paper §8).
     with_fisher: bool = False
     last_fisher: Optional[Dict] = field(default=None, repr=False)
+    # finetune metrics of the latest contribute() ({"loss": [...], ...})
+    last_metrics: Optional[Dict] = field(default=None, repr=False)
     _head: Optional[Dict] = field(default=None, repr=False)
     _iter: int = 0
 
@@ -47,7 +49,7 @@ class Contributor:
         """One ColD iteration: finetune the downloaded base on local data and
         return the updated body (the upload)."""
         head = self._ensure_head()
-        body, head, _ = FT.finetune(
+        body, head, self.last_metrics = FT.finetune(
             self.cfg, base_body, head, self.x, self.y,
             steps=self.steps, batch_size=self.batch_size, lr=self.lr,
             seed=self.seed * 1000 + self._iter,

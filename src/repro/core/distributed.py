@@ -99,15 +99,11 @@ def make_fuse_step(cfg: ArchConfig, mesh: Mesh, schedule: ColdSchedule,
     as the oracle.
 
     This shares the Repository fuse's implementation (the same layout, the
-    same partial+one-all-reduce structure — only the reduced dim differs)
-    and it *retires* the old GSPMD workaround: jax 0.4.37 CPU miscompiled
-    ``concat -> mean`` over a sharded leading axis into a SUM when the
-    concat inputs carried heterogeneous shardings, which previously forced
-    every piece to be pinned to ``P(contrib, None)`` — replicating the
-    staged buffer over the model/replica axes.  With the mean computed
-    manually under ``shard_map`` no GSPMD mean ever lowers, no pin is
-    needed, and each device holds only its ``1/S`` block-cyclic slice of
-    the buffer through the fuse.
+    same partial+one-all-reduce structure — only the reduced dim differs).
+    The mean is computed manually under ``shard_map``, so GSPMD never
+    lowers a ``concat -> mean`` over a sharded axis, no concat input needs
+    a sharding pin, and each device holds only its ``1/S`` block-cyclic
+    slice of the buffer through the fuse.
     """
 
     def leaf_fuse(x):

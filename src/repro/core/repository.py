@@ -88,6 +88,14 @@ FLAT_OPS = ("average", "damped", "task_arithmetic")
 MANIFEST = "staging_manifest.json"
 SKETCH_FILE = "cohort_sketch.json"
 
+
+class NothingToFuse(RuntimeError):
+    """The repository declined a fuse: nothing is staged, or the §9 screen
+    rejected every contribution.  The service loop treats it as a per-
+    cohort outcome and keeps running; any other error (a device or
+    compile failure) propagates."""
+
+
 # on-disk artifact naming in the npz root (compact() walks these)
 _BASE_RE = re.compile(r"^base_iter(\d{4,})\.npz$")
 _ROW_RE = re.compile(r"^iter\d{4,}_contrib\d{3,}\.npz$")
@@ -833,7 +841,7 @@ class Repository:
                 norm = norms_from_sq(jax.device_get(sq))[0]
                 report = screen_norms([norm], mad_threshold=self.mad_threshold)
                 if not report.accepted:
-                    raise RuntimeError(f"async contribution rejected: {report.reasons}")
+                    raise NothingToFuse(f"async contribution rejected: {report.reasons}")
             fused.block_until_ready()
             if self.mesh is not None:
                 new_base = self._spec.unflatten(self._sspec.unshard(fused))
@@ -845,7 +853,7 @@ class Repository:
                 report = screen_contributions(
                     self._base, [params], mad_threshold=self.mad_threshold)
                 if not report.accepted:
-                    raise RuntimeError(f"async contribution rejected: {report.reasons}")
+                    raise NothingToFuse(f"async contribution rejected: {report.reasons}")
             new_base = fusion.damped(self._base, [params], alpha=a)
             new_flat = None
         rec = FusionRecord(
@@ -903,7 +911,7 @@ class Repository:
         if alpha is not None or screen is not None or op is not None:
             raise ValueError("alpha=/screen=/op= overrides require buffer=")
         if not self._pending:
-            raise RuntimeError("no contributions to fuse")
+            raise NothingToFuse("no contributions to fuse")
         t0 = time.time()
         if not self.use_flat:
             with self._manifest_lock:
@@ -989,6 +997,10 @@ class Repository:
         take the historical path unchanged (a stacked ``StagedBuffer``,
         donation-eligible); any delta-compressed submission among the rows
         yields a ``MixedStage`` instead."""
+        for p in back.rows:
+            fut = self._row_futures.get(p) if isinstance(p, str) else None
+            if fut is not None:
+                fut.result()  # the peek below reads the spilled file
         if any(isinstance(p, str) and ckpt.is_flat_compressed(p)
                for p in back.rows):
             return self._stage_mixed(back)
@@ -1085,7 +1097,7 @@ class Repository:
             report = screen_norms(norms, mad_threshold=self.mad_threshold)
             n_accepted = len(report.accepted)
             if not report.accepted:
-                raise RuntimeError(f"all contributions rejected: {report.reasons}")
+                raise NothingToFuse(f"all contributions rejected: {report.reasons}")
             if report.rejected:
                 w2 = np.asarray(jax.device_get(pf.weights), np.float32).copy()
                 w2[report.rejected] = 0.0
@@ -1276,7 +1288,7 @@ class Repository:
             fishers = [fishers[i] for i in report.accepted]
             weights = [weights[i] for i in report.accepted]
             if not models:
-                raise RuntimeError(f"all contributions rejected: {report.reasons}")
+                raise NothingToFuse(f"all contributions rejected: {report.reasons}")
         kw = dict(self.fusion_kwargs)
         if self.fusion_op == "fisher":
             if any(f is None for f in fishers):

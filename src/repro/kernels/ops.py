@@ -14,7 +14,6 @@ from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ref
@@ -134,11 +133,11 @@ def _sharded_fuse_fn(mesh: Mesh, axes: Tuple[str, ...], use_kernel: bool):
             base[0], contribs[:, 0, :], weights, alpha[0], use_kernel=use_kernel)
         return fused[None], jax.lax.psum(sq, axes)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(row_spec, stage_spec, P(), P()),
         out_specs=(row_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -178,9 +177,9 @@ def _cohort_fuse_fn(mesh: Mesh, contrib_axes: Tuple[str, ...],
     Same sharded-flat structure as ``_sharded_fuse_fn`` with the roles of
     the axes swapped: here the *contributor* dim is the sharded reduction
     dim, so the per-shard partial is the local weighted sum over C_local and
-    the single psum (over the contributor axes) completes the mean — no
-    GSPMD ``concat -> mean`` ever lowers, which is what retires the jax
-    0.4.37 miscompile workaround (see docs/sharding.md)."""
+    the single psum (over the contributor axes) completes the mean, so no
+    GSPMD ``concat -> mean`` over a sharded axis ever lowers and the stage
+    needs no sharding pin (see docs/sharding.md)."""
     in_spec = P(axes_entry(contrib_axes),
                 axes_entry(shard_axes) if shard_axes else None, None)
     c_axes = axes_extent(mesh, contrib_axes)
@@ -196,8 +195,8 @@ def _cohort_fuse_fn(mesh: Mesh, contrib_axes: Tuple[str, ...],
             fused = jnp.broadcast_to(mean, xf.shape)
         return fused.astype(x.dtype)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(in_spec,),
-                   out_specs=in_spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(in_spec,),
+                       out_specs=in_spec, check_vma=False)
     return jax.jit(fn)
 
 
@@ -262,22 +261,26 @@ def decode_accum(indices, values, scales, weights, *,
 _ref_decode = jax.jit(ref.decode_accum, static_argnames=("size", "block"))
 
 
-@functools.partial(jax.jit, donate_argnums=())
-def _compressed_combine(base, acc, comp_weights, sq_comp,
-                        dense, dense_weights, alpha):
+def _combine_math(base, acc, comp_weights, sq_comp, dense, dense_weights,
+                  alpha):
     """Finish the compressed fuse from the decoded accumulator: combined
     normalization over dense + compressed weights, zero-weight masking on
-    the dense side, sq ordered (dense..., compressed...)."""
+    the dense side, sq ordered (dense..., compressed...).  The weighted sum
+    is an f32 elementwise reduction, not a contraction, so no backend runs
+    it at reduced matmul precision."""
     bf = base.astype(jnp.float32)
     wd = dense_weights.astype(jnp.float32)
     wc = comp_weights.astype(jnp.float32)
     w_tot = jnp.sum(wd) + jnp.sum(wc)
     df = dense.astype(jnp.float32)
     masked = jnp.where((wd == 0.0)[:, None], 0.0, df)
-    num = jnp.einsum("k,kn->n", wd, masked) + jnp.sum(wc) * bf + acc
+    num = jnp.sum(wd[:, None] * masked, axis=0) + jnp.sum(wc) * bf + acc
     fused = (bf + alpha * (num / w_tot - bf)).astype(base.dtype)
     sq_dense = jnp.sum(jnp.square(df - bf[None, :]), axis=1)
     return fused, jnp.concatenate([sq_dense, sq_comp])
+
+
+_compressed_combine = jax.jit(_combine_math)
 
 
 def fuse_flat_compressed(
@@ -332,22 +335,13 @@ def _compressed_sharded_fn(mesh: Mesh, axes: Tuple[str, ...], block: int,
         return ref.decode_accum(idx.astype(jnp.int32), dv, wc,
                                 size=length, block=block)
 
-    def _local_math(base, acc, wc, sq_comp, dense, wd, alpha):
-        bf = base.astype(jnp.float32)
-        w_tot = jnp.sum(wd) + jnp.sum(wc)
-        masked = jnp.where((wd == 0.0)[:, None], 0.0, dense.astype(jnp.float32))
-        num = jnp.einsum("k,kn->n", wd, masked) + jnp.sum(wc) * bf + acc
-        fused = (bf + alpha * (num / w_tot - bf)).astype(base.dtype)
-        sq_dense = jnp.sum(jnp.square(dense.astype(jnp.float32) - bf[None, :]), axis=1)
-        return fused, jnp.concatenate([sq_dense, sq_comp])
-
     if has_dense:
         def local(base, idx, val, scl, wc, dense, wd, alpha):
             # local blocks carry a size-1 stub of the shard dim: strip it
             acc, sq_comp = _local_decode(
                 idx[:, 0], val[:, 0], scl[:, 0], wc, base.shape[1])
-            fused, sq = _local_math(base[0], acc, wc, sq_comp,
-                                    dense[:, 0, :], wd, alpha[0])
+            fused, sq = _combine_math(base[0], acc, wc, sq_comp,
+                                      dense[:, 0, :], wd, alpha[0])
             return fused[None], jax.lax.psum(sq, axes)
 
         in_specs = (row_spec, comp_spec, comp_spec, scl_spec, P(),
@@ -358,17 +352,17 @@ def _compressed_sharded_fn(mesh: Mesh, axes: Tuple[str, ...], block: int,
                 idx[:, 0], val[:, 0], scl[:, 0], wc, base.shape[1])
             dense = jnp.zeros((0, base.shape[1]), base.dtype)
             wd = jnp.zeros((0,), jnp.float32)
-            fused, sq = _local_math(base[0], acc, wc, sq_comp,
-                                    dense, wd, alpha[0])
+            fused, sq = _combine_math(base[0], acc, wc, sq_comp,
+                                      dense, wd, alpha[0])
             return fused[None], jax.lax.psum(sq, axes)
 
         in_specs = (row_spec, comp_spec, comp_spec, scl_spec, P(), P())
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=in_specs,
         out_specs=(row_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -442,8 +436,8 @@ def _sharded_sketch_fn(mesh: Mesh, axes: Tuple[str, ...], n_shards: int,
         part = ref.row_sketch_shard(row[0], idx, n_shards, block, n_buckets)
         return jax.lax.psum(part, axes)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(row_spec,), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(row_spec,), out_specs=P(),
+                       check_vma=False)
     return jax.jit(fn)
 
 

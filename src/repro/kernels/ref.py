@@ -28,14 +28,14 @@ def cold_fuse(
     Zero-weight contributions are masked out of the average entirely (even
     non-finite ones — NaN·0 must not poison the sum), matching the Pallas
     kernel's single-pass screen+fuse contract; sq_diff always reflects the
-    raw values.
+    raw values.  The weighted sum is an f32 elementwise reduction, not a
+    contraction, so no backend runs it at reduced matmul precision.
     """
     w = weights.astype(jnp.float32)
-    wn = w / jnp.sum(w)
     cf = contribs.astype(jnp.float32)
     bf = base.astype(jnp.float32)
     masked = jnp.where((w == 0.0)[:, None], 0.0, cf)
-    avg = jnp.einsum("k,kn->n", wn, masked)
+    avg = jnp.sum((w / jnp.sum(w))[:, None] * masked, axis=0)
     fused = (bf + alpha * (avg - bf)).astype(base.dtype)
     sq = jnp.sum(jnp.square(cf - bf[None, :]), axis=1)
     return fused, sq
@@ -95,11 +95,13 @@ def _bucketize(ts: jax.Array, tq: jax.Array, g: jax.Array,
                n_buckets: int) -> jax.Array:
     """Accumulate per-tile stats into their buckets (tile with global index
     ``g`` lands in bucket ``g % n_buckets``).  Dense one-hot matmul instead
-    of a scatter: ``n_buckets`` is small and the same contraction lowers on
-    every backend (including the Pallas TPU kernel, where scatters do not)."""
+    of a scatter: ``n_buckets`` is small and the contraction lowers on
+    every backend."""
     onehot = (g[:, None] % n_buckets
               == jnp.arange(n_buckets)[None, :]).astype(jnp.float32)
-    return jnp.stack([ts @ onehot, tq @ onehot])
+    hi = jax.lax.Precision.HIGHEST  # f32 sums, not one bf16 MXU pass
+    return jnp.stack([jnp.matmul(ts, onehot, precision=hi),
+                      jnp.matmul(tq, onehot, precision=hi)])
 
 
 def row_sketch(row: jax.Array, n_buckets: int = 32) -> jax.Array:

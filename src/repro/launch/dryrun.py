@@ -41,6 +41,7 @@ from repro.configs.shapes import SHAPES
 from repro.core.distributed import make_cold_train_step, make_fuse_step, ColdSchedule
 from repro.kernels import ops as KOPS
 from repro.launch import sharding as SH
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_cold_mesh, make_production_mesh
 from repro.launch.specs import (
     abstract_cache,
@@ -290,6 +291,7 @@ def _artifact_path(arch: str, shape: str, mesh_kind: str, strategy: str) -> str:
 
 
 def main() -> int:
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", choices=list(ARCH_IDS), default=None)
     p.add_argument("--shape", choices=list(SHAPES), default=None)

@@ -14,11 +14,13 @@ import numpy as np
 
 from repro.checkpoint import io as ckpt
 from repro.configs import ARCH_IDS, get_config, reduce_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_lm
 from repro.serve.engine import Engine
 
 
 def main() -> None:
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", choices=list(ARCH_IDS), default="gemma3-1b")
     p.add_argument("--reduced", action="store_true")

@@ -50,6 +50,8 @@ host_tuning.maybe_reexec()
 
 from repro.checkpoint import io as ckpt
 from repro.core.repository import Repository, RepositoryFamily
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.serve.cold_service import AdmissionPolicy, ColdService
 from repro.serve.probes import ProbeSuite, RegressionGate
 
@@ -63,7 +65,7 @@ def build_service(args) -> ColdService:
                 f"--mesh {args.mesh} needs {args.mesh} devices, have "
                 f"{jax.device_count()} (set XLA_FLAGS="
                 f"--xla_force_host_platform_device_count={args.mesh})")
-        mesh = jax.make_mesh((args.mesh,), ("model",))
+        mesh = make_mesh((args.mesh,), ("model",))
     kw = dict(spill=True, spill_workers=args.spill_workers)
     if mesh is not None:
         kw["mesh"] = mesh
@@ -118,6 +120,7 @@ def build_service(args) -> ColdService:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     p = argparse.ArgumentParser(
         description="queue-driven ColD Fusion daemon (docs/service_loop.md)")
     p.add_argument("--root", required=True, help="repository npz root")

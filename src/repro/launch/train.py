@@ -25,6 +25,7 @@ import numpy as np
 from repro.checkpoint import io as ckpt
 from repro.configs import ARCH_IDS, get_config, reduce_config
 from repro.data.synthetic import SyntheticSuite
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import whisper as W
 from repro.models.transformer import init_lm
 from repro.optim.optimizers import make_optimizer, warmup_cosine_lr
@@ -38,6 +39,7 @@ def build_params(cfg, key):
 
 
 def main() -> None:
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", choices=list(ARCH_IDS), default="gemma3-1b")
     p.add_argument("--reduced", action="store_true",

@@ -79,8 +79,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.checkpoint import io as ckpt
-from repro.core.repository import (Repository, RepositoryFamily,
-                                   family_member_root)
+from repro.core.repository import (NothingToFuse, Repository,
+                                   RepositoryFamily, family_member_root)
 from repro.serve.probes import RegressionGate
 from repro.utils import faults
 from repro.utils.flat import (LANE, FamilyRouter, FlatSpec, ShardedFlatSpec,
@@ -1363,7 +1363,7 @@ class ColdService:
                     lane.cohort_since = None
                     self._last_error = None
                     faults.crash_point("service.post_dispatch")
-                except RuntimeError as err:  # e.g. all rows rejected
+                except NothingToFuse as err:  # e.g. all rows rejected
                     self._note_error(err, lane)
             elif lane.repo.inflight:
                 # queue drained: publish the in-flight fuse instead of
@@ -1371,7 +1371,7 @@ class ColdService:
                 try:
                     lane.repo.flush()
                     self._last_error = None
-                except RuntimeError as err:
+                except NothingToFuse as err:
                     self._note_error(err, lane)
             if lane.repo.iteration != it_before:
                 published.append(lane)
@@ -1544,7 +1544,7 @@ class ColdService:
         for lane in list(self._lanes.values()):
             try:
                 lane.repo.flush()
-            except RuntimeError as err:
+            except NothingToFuse as err:
                 self._note_error(err, lane)
         self._gc_consumed()
         st = self.status()

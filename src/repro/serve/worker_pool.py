@@ -47,6 +47,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.checkpoint import io as ckpt
@@ -135,7 +136,12 @@ class WorkerPool:
     dies at an exact swap seam while its peers keep serving.  Children
     inherit the parent environment minus ``XLA_FLAGS`` (a forced
     fake-device mesh belongs to the fusion daemon, not the CPU serving
-    children)."""
+    children).
+
+    The children are CPU serving processes that each import JAX.  On a
+    TPU host the parent (the fusion daemon) already holds the chip, and a
+    child that reaches for it fails or hangs, so ``start`` refuses when
+    the parent's backend is ``tpu``."""
 
     def __init__(self, root: str, n_workers: int, *, arch: str = None,
                  engine: str = "real", max_len: int = 64,
@@ -199,6 +205,11 @@ class WorkerPool:
 
     def start(self, *, timeout: float = 60.0) -> "WorkerPool":
         """Spawn every child and wait until each registered its port."""
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "WorkerPool spawns serving children that import JAX, and "
+                "this process holds the TPU: a child would fail or hang "
+                "reaching for it.  Serve in-process on a TPU host.")
         for wid in self.worker_ids:
             self._procs[wid] = self._spawn(wid)
         self.endpoints = [SocketEndpoint(self.root, wid)

@@ -300,6 +300,29 @@ def test_screen_outlier_diluted_not_fatal(tmp_path):
     assert np.isfinite(np.asarray(svc.repo.download()["w"])).all()
 
 
+@pytest.mark.parametrize("seam", ["fuse_pending", "flush"])
+def test_device_error_in_fuse_propagates(tmp_path, monkeypatch, seam):
+    """Only the repository's own refusal (NothingToFuse) is a per-cohort
+    outcome: a device error raised by the fuse (a compile failure, an HBM
+    out-of-memory) leaves run_once instead of landing in the error ring."""
+    import jax
+
+    root = str(tmp_path / "repo")
+    svc = ColdService(_make(root), policy=AdmissionPolicy(min_cohort=1))
+    ContributorClient(root, name="c0").submit(_m(1.0))
+    if seam == "flush":
+        svc.run_once()  # dispatch; the next cycle's flush publishes
+        assert svc.repo.inflight
+
+    def boom(*a, **k):
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: injected")
+
+    monkeypatch.setattr(svc.repo, seam, boom)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="injected"):
+        svc.run_once()
+    assert svc.status()["last_error"] is None
+
+
 def test_status_endpoint_fields(tmp_path):
     root = str(tmp_path / "repo")
     svc = ColdService(_make(root), policy=AdmissionPolicy(min_cohort=2))

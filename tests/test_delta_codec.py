@@ -19,6 +19,7 @@ from repro.checkpoint import io as ckpt
 from repro.core.repository import Repository
 from repro.kernels import ops, ref
 from repro.kernels.cold_fuse import decode_accum as kernel_decode_accum
+from repro.launch.mesh import make_mesh
 from repro.utils.flat import (LANE, MAX_DELTA_BLOCK, DeltaPayload, FlatSpec,
                               ShardedFlatSpec, delta_checksum, delta_decode,
                               delta_decode_sharded, delta_encode,
@@ -38,7 +39,7 @@ def _row(n, seed=0, scale=1.0):
 
 def _mesh(axis="model"):
     n = jax.device_count()
-    return jax.make_mesh((n,), (axis,)), n
+    return make_mesh((n,), (axis,)), n
 
 
 # ---------------------------------------------------------------------------
@@ -50,7 +51,7 @@ def _mesh(axis="model"):
     n=st.integers(1, 3 * LANE + 200),
     kb=st.integers(0, 96),
     seed=st.integers(0, 10_000),
-    scale=st.floats(min_value=1e-3, max_value=100.0, width=32),
+    scale=st.floats(min_value=float(np.float32(1e-3)), max_value=100.0, width=32),
 )
 @settings(max_examples=20, deadline=None)
 def test_roundtrip_error_bounds(n, kb, seed, scale):
@@ -75,11 +76,13 @@ def test_roundtrip_error_bounds(n, kb, seed, scale):
         bound = max(pay.scales[b] / 2.0, min_kept) * (1 + 1e-5) + 1e-7
         e = err[b * block:(b + 1) * block]
         assert e.size == 0 or e.max() <= bound, (b, e.max(), bound)
-    # sq statistic of the decoded delta never exceeds the true delta's
+    # sq statistic of the decoded delta exceeds the true delta's by at most
+    # the half-step rounding of each kept entry
     dv = np.zeros((nb * block,), np.float32)
     gi, vv = delta_entries(pay)
     np.add.at(dv, gi, vv)
-    assert np.sum(dv * dv) <= np.sum(d * d) * (1 + 1e-4) + 1e-6
+    kept_bound = np.sum(np.square(np.abs(pad[gi]) + pay.scales[gi // block] / 2))
+    assert np.sum(dv * dv) <= kept_bound * (1 + 1e-5) + 1e-7
 
 
 @given(n=st.integers(1, 2 * LANE + 50), seed=st.integers(0, 1000))

@@ -38,6 +38,34 @@ def test_cold_fuse_sweep(K, N, dtype, alpha):
     np.testing.assert_allclose(np.asarray(sq_k), np.asarray(sq_ref), rtol=1e-4)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_compressed_combine_matches_decoded_rows(dtype):
+    """The compressed fuse's finish from the decoded accumulator equals the
+    plain fuse over the decoded rows ``base + Δ_c`` (zero-weight dense rows
+    still masked, even non-finite ones)."""
+    ks = jax.random.split(KEY, 4)
+    K, C, N = 3, 2, 5000
+    base = jax.random.normal(ks[0], (N,), jnp.float32).astype(dtype)
+    dense = jax.random.normal(ks[1], (K, N), jnp.float32).astype(dtype)
+    dense = dense.at[0].set(jnp.nan)
+    deltas = jax.random.normal(ks[2], (C, N), jnp.float32) * 0.1
+    wd = jnp.asarray([0.0, 1.5, 0.5], jnp.float32)
+    wc = jnp.asarray([1.0, 2.0], jnp.float32)
+    acc = jnp.sum(wc[:, None] * deltas, axis=0)
+    sq_comp = jnp.sum(deltas * deltas, axis=1)
+    fused, sq = ops._compressed_combine(base, acc, wc, sq_comp, dense, wd,
+                                        jnp.float32(0.7))
+    rows = jnp.concatenate(
+        [dense.astype(jnp.float32), base.astype(jnp.float32) + deltas])
+    f_rows, sq_rows = ref.cold_fuse(base.astype(jnp.float32), rows,
+                                    jnp.concatenate([wd, wc]), 0.7)
+    np.testing.assert_allclose(np.asarray(fused, np.float32),
+                               np.asarray(f_rows), atol=_tol(dtype))
+    np.testing.assert_allclose(np.asarray(sq)[1:], np.asarray(sq_rows)[1:],
+                               rtol=1e-4)
+    assert np.isnan(np.asarray(sq)[0])
+
+
 def test_cold_fuse_uniform_weights_is_mean():
     base = jnp.zeros((256,))
     contribs = jnp.stack([jnp.full((256,), float(i)) for i in range(4)])

@@ -383,9 +383,10 @@ import os, sys
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
 from repro.core.repository import Repository
+from repro.launch.mesh import make_mesh
 root, phase = sys.argv[1], sys.argv[2]
 assert jax.device_count() == 8, jax.device_count()
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 def m(v):
     return {"w": jnp.full((3000,), float(v)), "b": jnp.full((17,), float(v))}
 if phase == "stage":

@@ -22,6 +22,7 @@ import pytest
 from repro.core.repository import Repository
 from repro.kernels import ops
 from repro.launch import sharding as SH
+from repro.launch.mesh import make_mesh
 from repro.utils.flat import LANE, ShardedFlatSpec, flatten_tree
 from repro.utils.hlo import collect_collectives
 
@@ -30,7 +31,7 @@ KEY = jax.random.PRNGKey(7)
 
 def _mesh(axis="model"):
     n = jax.device_count()
-    return jax.make_mesh((n,), (axis,)), n
+    return make_mesh((n,), (axis,)), n
 
 
 def _odd_tree(key, scale=1.0):
@@ -367,7 +368,7 @@ def test_repository_mesh_forces_flat_even_without_kernels():
 def test_cohort_fuse_sharded_matches_per_leaf():
     """ops.cohort_fuse_sharded == the per-leaf mean/lerp oracle, for both
     plain and damped fusion, on a contrib-only mesh."""
-    mesh = jax.make_mesh((jax.device_count(),), ("contrib",))
+    mesh = make_mesh((jax.device_count(),), ("contrib",))
     C, N = 2 * jax.device_count(), 5000  # slabs divide the contributor axis
     buf = jax.random.normal(KEY, (C, N))
     for alpha in (1.0, 0.3):
@@ -394,12 +395,13 @@ import jax, jax.numpy as jnp
 import numpy as np
 from repro.core.repository import Repository
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.utils.flat import ShardedFlatSpec
 from repro.utils.hlo import collect_collectives
 from repro.launch import sharding as SH
 
 assert jax.device_count() == 8
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 
 def tree(key, scale=1.0):
     ks = jax.random.split(key, 3)

@@ -19,6 +19,7 @@ from repro.checkpoint import io as ckpt
 from repro.core.repository import SKETCH_FILE, Repository
 from repro.kernels import ops, ref
 from repro.kernels.cold_fuse import row_sketch as kernel_row_sketch
+from repro.launch.mesh import make_mesh
 from repro.utils.flat import (LANE, CohortSketch, ShardedFlatSpec,
                               row_sketch_host)
 from repro.utils.hlo import collect_collectives
@@ -86,7 +87,7 @@ def test_shard_partials_sum_to_portable_sketch(s, n):
 
 def _mesh(axis="model"):
     n = jax.device_count()
-    return jax.make_mesh((n,), (axis,)), n
+    return make_mesh((n,), (axis,)), n
 
 
 def test_row_sketch_sharded_matches_single_device():

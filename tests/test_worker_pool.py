@@ -326,6 +326,19 @@ def test_worker_id_rejects_path_characters():
             serving_state_filename(bad)
 
 
+def test_pool_refuses_to_spawn_from_a_tpu_parent(tmp_path, monkeypatch):
+    """A parent holding the TPU must not start JAX children: start()
+    raises before spawning anything."""
+    import jax
+    from repro.serve.worker_pool import WorkerPool
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pool = WorkerPool(str(tmp_path), 2, engine="value")
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        pool.start()
+    assert pool._procs == {}
+
+
 # ---------------------------------------------------------------------------
 # cross-process pool (slow): socket protocol, kill -9, swap-seam crash
 # ---------------------------------------------------------------------------
