@@ -1,47 +1,14 @@
-"""Benchmark entry point: one module per paper table/figure + kernel micro +
-the dry-run roofline table.  Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark entry point: one module per paper table/figure, the serving
+load harness and the dry-run roofline table.  Prints
+``name,us_per_call,derived`` CSV.  The on-chip benchmark is ``bench/``.
 
-Kernel-level rows (``kernel/*`` and ``fuse_e2e/*``) are also written to
-``BENCH_kernels.json`` at the repo root so the perf trajectory of the
-Repository hot path survives across PRs.
-
-  PYTHONPATH=src python -m benchmarks.run [--only fig2,fig5] [--skip-main]
+  PYTHONPATH=src python -m benchmarks.run [--only fig2,fig5]
   REPRO_BENCH_SCALE=quick|std|full
 """
 import argparse
-import datetime
-import json
-import os
 import sys
 import time
 import traceback
-
-_KERNEL_PREFIXES = ("kernel/", "fuse_e2e/", "service_loop/", "serve_load/")
-_BENCH_JSON = os.path.join(os.path.dirname(__file__), "..", "BENCH_kernels.json")
-
-
-def _emit_kernel_json(rows) -> None:
-    entries = {}
-    for r in rows.rows:
-        if not r.startswith(_KERNEL_PREFIXES):
-            continue
-        name, us, derived = r.split(",", 2)
-        entries[name] = {"us_per_call": float(us), "derived": derived}
-    if not entries:
-        return
-    import jax  # deferred: only the benches themselves need jax otherwise
-
-    payload = {
-        "generated": datetime.date.today().isoformat(),
-        "scale": os.environ.get("REPRO_BENCH_SCALE", "std"),
-        # pallas_interp rows run the interpret-mode harness regardless of
-        # backend; the rest use the backend named here
-        "backend": jax.default_backend(),
-        "entries": entries,
-    }
-    with open(_BENCH_JSON, "w") as f:
-        json.dump(payload, f, indent=2)
-    print(f"# wrote {os.path.normpath(_BENCH_JSON)}")
 
 
 def main() -> None:
@@ -52,13 +19,10 @@ def main() -> None:
     from benchmarks import common as C
     from benchmarks import (appE_scale, appF_fixed_examples, beyond_fusion_ops,
                             fig2_main, fig3_unseen, fig4_fewshot, fig5_contributors,
-                            fig6_single_dataset, fuse_e2e, kernels_micro, roofline,
-                            serve_load, service_loop, table1_per_task)
+                            fig6_single_dataset, roofline, serve_load,
+                            table1_per_task)
 
     benches = {
-        "kernels": kernels_micro.run,
-        "fuse_e2e": fuse_e2e.run,
-        "service_loop": service_loop.run,
         "serve_load": serve_load.run,
         "fig2": fig2_main.run,
         "fig3": fig3_unseen.run,
@@ -86,7 +50,6 @@ def main() -> None:
             traceback.print_exc(file=sys.stderr)
         rows.rows.append(f"# {name} done in {time.time()-t1:.0f}s")
     rows.emit()
-    _emit_kernel_json(rows)
     print(f"# total {time.time()-t0:.0f}s scale={C.SCALE}")
 
 

@@ -113,16 +113,9 @@ test -s "$SCALE_ROOT/metrics.png"
 rm -rf "$SCALE_ROOT"
 python -m pytest tests/test_worker_pool.py -q -m slow
 
-# kernel + end-to-end fuse micro-benches (smoke scale); refreshes
-# BENCH_kernels.json (including the fuse_e2e/mesh8_sharded,
-# fuse_e2e/async_overlap, service_loop/throughput,
-# service_loop/delta_compression, service_loop/routed_fusion, and
-# serve_load/hot_swap rows — the delta row asserts >=5x queue-bytes
-# reduction and codec parity, the routed row asserts single-base fuse
-# parity AND two-stream separation, the hot-swap row asserts zero
-# failed/torn requests across >=3 live swaps, before posting) so the
-# perf trajectory stays current
-REPRO_BENCH_SCALE=quick python -m benchmarks.run --only kernels,fuse_e2e,service_loop,serve_load
+# the serving load bench (smoke scale): its hot-swap row asserts zero
+# failed/torn requests across >=3 live swaps before posting
+REPRO_BENCH_SCALE=quick python -m benchmarks.run --only serve_load
 
 # examples cannot silently rot: both must run end-to-end at dry-run scale
 python examples/cold_fusion_multitask.py --dry-run

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils import trace
 from repro.utils.flat import DeltaPayload, FlatSpec, ShardedFlatSpec
 from repro.utils.pytree import path_str
 
@@ -48,6 +49,12 @@ _DELTA_SPEC = "__delta_spec__"      # codec geometry (compressed submissions)
 _DELTA_IDX = "__delta_indices__"    # int16 [nb, kb] (or [S, nb, kb])
 _DELTA_VAL = "__delta_values__"     # int8  [nb, kb] (or [S, nb, kb])
 _DELTA_SCL = "__delta_scales__"     # f32   [nb]     (or [S, nb])
+
+
+def _count_file(counter: str, path: str) -> None:
+    """Add ``path``'s size to the trace counter (only while tracing)."""
+    if trace.enabled():
+        trace.count(counter, os.path.getsize(path))
 
 
 def _flatten(tree) -> Dict[str, np.ndarray]:
@@ -89,6 +96,7 @@ def _atomic_savez(path: str, arrays: Dict[str, np.ndarray]) -> None:
         # np.savez itself appends .npz when the target lacks the suffix
         if not tmp.endswith(".npz") and os.path.exists(tmp + ".npz"):
             tmp += ".npz"
+        _count_file("io.write_bytes", tmp)
         os.replace(tmp, path)
     except BaseException:
         for cand in (tmp, tmp + ".npz"):
@@ -145,6 +153,7 @@ def load_flat(path: str, *, as_jax: bool = True) -> Tuple[Any, FlatSpec]:
         meta = json.loads(bytes(data[_FLAT_SPEC]).decode())
         spec = FlatSpec.from_json(meta)
         buf = data[_FLAT_BUF]
+    _count_file("io.read_bytes", path)
     if spec.dtype == "bfloat16":
         buf = buf.view(jnp.bfloat16)
     if as_jax:
@@ -174,6 +183,7 @@ def save_json_atomic(path: str, obj: Any, *, default=None,
     try:
         with open(tmp, "w") as f:
             json.dump(obj, f, indent=indent, default=default)
+        _count_file("io.write_bytes", tmp)
         os.replace(tmp, path)
     except BaseException:
         if os.path.exists(tmp):
@@ -507,6 +517,7 @@ def load_flat_delta(path: str) -> Tuple[list, Dict[str, Any]]:
             if k not in data.files:
                 raise ValueError(f"{path}: missing delta entry {k}")
         idx, val, scl = data[_DELTA_IDX], data[_DELTA_VAL], data[_DELTA_SCL]
+    _count_file("io.read_bytes", path)
     if not sharded:
         idx, val, scl = idx[None], val[None], scl[None]
     n = idx.shape[0]

@@ -75,7 +75,7 @@ from repro.core.validation import (ScreenReport, norms_from_sq,
                                    screen_contributions, screen_norms)
 from repro.kernels import ops
 from repro.launch import sharding as SH
-from repro.utils import faults
+from repro.utils import faults, trace
 from repro.utils.flat import (SKETCH_BUCKETS, BufferPair, CohortSketch,
                               FlatSpec, ShardedFlatSpec, StagedBuffer,
                               StagingSide, delta_decode, delta_decode_sharded,
@@ -108,7 +108,12 @@ class FusionRecord:
     n_accepted: int
     op: str
     diff_norms: List[float]
+    # the cadence: from the fuse's start to its publish, idle cycles included
     wall_time: float
+    # host seconds the fuse took: staging and dispatch, then the finalize
+    # (its wait on the device included); their sum is ``fuse_latency_s``
+    stage_s: float = 0.0
+    finalize_s: float = 0.0
 
 
 @dataclass
@@ -127,6 +132,7 @@ class PendingFusion:
     k: int
     t0: float
     record: Optional[FusionRecord] = None
+    stage_s: float = 0.0
     # per-fusion overrides (fuse_pending(buffer=..., alpha=/screen=/op=));
     # None defers to the repository's configuration
     alpha: Optional[float] = None
@@ -163,6 +169,12 @@ class MixedStage:
         return len(self.dense_pos) + len(self.comp_pos)
 
 
+def stage_stack(*rows):
+    """K staged rows -> the ``[K, ...]`` fuse operand (``jit_stage_stack``
+    in a profiler trace)."""
+    return jnp.stack(rows)
+
+
 @functools.lru_cache(maxsize=32)
 def _stack_fn(k: int, sharding):
     """Jitted K-row stack with the staging out-sharding: each device
@@ -170,7 +182,7 @@ def _stack_fn(k: int, sharding):
     cohort onto one device.  Cached per (K, sharding) to avoid re-tracing
     every fuse."""
     del k  # shapes key the jit cache; K only keys the lru entry
-    return jax.jit(lambda *rows: jnp.stack(rows), out_shardings=sharding)
+    return jax.jit(stage_stack, out_shardings=sharding)
 
 
 @functools.lru_cache(maxsize=32)
@@ -180,7 +192,7 @@ def _stack_plain_fn(k: int):
     — and the stack must dispatch async for the double-buffered fuse to
     overlap uploads (docs/async_repository.md)."""
     del k
-    return jax.jit(lambda *rows: jnp.stack(rows))
+    return jax.jit(stage_stack)
 
 
 def _json_default(o):
@@ -376,20 +388,24 @@ class Repository:
                 # mesh): fall back to host reassembly + restage
                 row = jnp.asarray(r.full_row())
             return self._stage_row(row) if self.mesh is not None else row
-        row, _ = ckpt.load_flat(p)
-        return row
+        with self._stage_span("repo.spill_read"):
+            row, _ = ckpt.load_flat(p, as_jax=False)
+        with self._stage_span("repo.h2d"):
+            return jnp.asarray(row)  # as load_flat(as_jax=True) puts it
 
     def _stack_stage(self, rows: List[jax.Array]) -> jax.Array:
         """Stack K staged rows into the fuse operand.  On a mesh the stack
         runs under jit with the staging out-sharding, so each device
         concatenates its local slices — the [K, N] buffer is never
         materialized on one device."""
-        if self.mesh is None:
-            return _stack_plain_fn(len(rows))(*rows)
-        rows = [r if r.ndim == 2 else self._stage_row(r) for r in rows]  # [N] rows re-shard
-        stack = _stack_fn(
-            len(rows), SH.flat_stage_sharding(self.mesh, self.mesh_axes))
-        return stack(*rows)
+        with self._stage_span("repo.stack"):
+            if self.mesh is None:
+                return _stack_plain_fn(len(rows))(*rows)
+            rows = [r if r.ndim == 2 else self._stage_row(r)
+                    for r in rows]  # [N] rows re-shard
+            stack = _stack_fn(
+                len(rows), SH.flat_stage_sharding(self.mesh, self.mesh_axes))
+            return stack(*rows)
 
     def _fuse_flat(self, stage, weights, alpha, *, donate: bool):
         if isinstance(stage, MixedStage):
@@ -456,8 +472,9 @@ class Repository:
 
     def _publish_flat(self, fused: jax.Array):
         """Fused flat buffer -> the new base pytree (+ cached flat form)."""
-        row = self._sspec.unshard(fused) if self.mesh is not None else fused
-        self._base = self._spec.unflatten(row)
+        with trace.span("repo.publish", iteration=self.iteration):
+            row = self._sspec.unshard(fused) if self.mesh is not None else fused
+            self._base = self._spec.unflatten(row)
         self._base_flat = fused
 
     # -- publish subscription (fuse-to-serve hot path) ------------------
@@ -482,6 +499,13 @@ class Repository:
         repository while a fuse is in flight (its publish will advance
         ``iteration`` before the staged cohort fuses)."""
         return self.iteration + (1 if self._inflight is not None else 0)
+
+    def _stage_span(self, name: str):
+        """A trace span of the staging work, tagged with the iteration it
+        stages for (read only while tracing is on)."""
+        if not trace.enabled():
+            return trace.span(name)
+        return trace.span(name, iteration=self._staging_iteration())
 
     def _contrib_path(self, idx: int) -> str:
         return os.path.join(
@@ -827,7 +851,7 @@ class Repository:
         rejects, the merged buffer is simply discarded."""
         self.flush()  # quiesce: its publish below must not race queued writes
         a = alpha if alpha is not None else 1.0 / (1.0 + self.iteration)
-        t0 = time.time()
+        t0, t_fin = time.time(), time.perf_counter()
         if self.use_flat:
             self._ensure_flat_base()
             row = self._spec.flatten(params)
@@ -859,6 +883,7 @@ class Repository:
         rec = FusionRecord(
             iteration=self.iteration, n_contributions=1, n_accepted=1,
             op=f"async-damped({a:.3f})", diff_norms=[], wall_time=time.time() - t0,
+            finalize_s=time.perf_counter() - t_fin,
         )
         self.history.append(rec)
         if self.keep_history:
@@ -912,13 +937,14 @@ class Repository:
             raise ValueError("alpha=/screen=/op= overrides require buffer=")
         if not self._pending:
             raise NothingToFuse("no contributions to fuse")
-        t0 = time.time()
+        t0, t_fin = time.time(), time.perf_counter()
         if not self.use_flat:
             with self._manifest_lock:
                 back = self._buffers.swap()
             self._mark_back_fusing()
             try:
                 rec = self._fuse_pending_pytree(t0, back)
+                rec.finalize_s = time.perf_counter() - t_fin
             except Exception:
                 self._restore_back()
                 raise
@@ -969,6 +995,7 @@ class Repository:
         without blocking: jax dispatch is asynchronous, so the device
         crunches while the host stages the next cohort.  The buffer is kept
         alive (no donation) only if a screening re-pass might need it."""
+        t_stage = time.perf_counter()
         self._ensure_flat_base()
         K = len(back.rows)
         stage = self._stage_cohort(back)
@@ -990,22 +1017,24 @@ class Repository:
         self._mark_back_fusing()
         return PendingFusion(
             stage=stage if self.screen else None,
-            fused=fused, sq=sq, weights=w, k=K, t0=t0)
+            fused=fused, sq=sq, weights=w, k=K, t0=t0,
+            stage_s=time.perf_counter() - t_stage)
 
     def _stage_cohort(self, back: StagingSide):
         """Build the fuse operand for the back cohort.  All-dense cohorts
         take the historical path unchanged (a stacked ``StagedBuffer``,
         donation-eligible); any delta-compressed submission among the rows
         yields a ``MixedStage`` instead."""
-        for p in back.rows:
-            fut = self._row_futures.get(p) if isinstance(p, str) else None
-            if fut is not None:
-                fut.result()  # the peek below reads the spilled file
-        if any(isinstance(p, str) and ckpt.is_flat_compressed(p)
-               for p in back.rows):
-            return self._stage_mixed(back)
-        rows = [self._load_staged_row(p) for p in back.rows]
-        return StagedBuffer(self._stack_stage(rows))
+        with self._stage_span("repo.stage"):
+            for p in back.rows:
+                fut = self._row_futures.get(p) if isinstance(p, str) else None
+                if fut is not None:
+                    fut.result()  # the peek below reads the spilled file
+            if any(isinstance(p, str) and ckpt.is_flat_compressed(p)
+                   for p in back.rows):
+                return self._stage_mixed(back)
+            rows = [self._load_staged_row(p) for p in back.rows]
+            return StagedBuffer(self._stack_stage(rows))
 
     def _stage_mixed(self, back: StagingSide):
         """Partition the back cohort into dense rows and compressed payload
@@ -1088,35 +1117,42 @@ class Repository:
         """The host half of the screen+fuse: pull sq_diff (the only device
         sync), apply the §9 decision rule, re-pass with zeroed weights on
         rejections, and publish."""
-        fused = pf.fused
-        report: Optional[ScreenReport] = None
-        n_accepted = pf.k
-        use_screen = self.screen if pf.use_screen is None else pf.use_screen
-        if use_screen:
-            norms = norms_from_sq(jax.device_get(pf.sq))
-            report = screen_norms(norms, mad_threshold=self.mad_threshold)
-            n_accepted = len(report.accepted)
-            if not report.accepted:
-                raise NothingToFuse(f"all contributions rejected: {report.reasons}")
-            if report.rejected:
-                w2 = np.asarray(jax.device_get(pf.weights), np.float32).copy()
-                w2[report.rejected] = 0.0
-                alpha = (self._flat_alpha(n_accepted) if pf.alpha is None
-                         else pf.alpha)
-                fused, _ = self._fuse_flat(
-                    pf.stage, jnp.asarray(w2), alpha, donate=True)
-        fused.block_until_ready()
-        rec = FusionRecord(
-            iteration=self.iteration,
-            n_contributions=pf.k,
-            n_accepted=n_accepted,
-            op=pf.op or self.fusion_op,
-            diff_norms=report.diff_norms if report else [],
-            wall_time=time.time() - pf.t0,
-        )
-        if self.keep_history:
-            self._snapshots.append(self._base)
-        self._publish_flat(fused)
+        t_fin = time.perf_counter()
+        with trace.span("repo.finalize", iteration=self.iteration):
+            fused = pf.fused
+            report: Optional[ScreenReport] = None
+            n_accepted = pf.k
+            use_screen = self.screen if pf.use_screen is None else pf.use_screen
+            if use_screen:
+                with trace.span("repo.screen_sync", iteration=self.iteration):
+                    sq = jax.device_get(pf.sq)
+                norms = norms_from_sq(sq)
+                report = screen_norms(norms, mad_threshold=self.mad_threshold)
+                n_accepted = len(report.accepted)
+                if not report.accepted:
+                    raise NothingToFuse(
+                        f"all contributions rejected: {report.reasons}")
+                if report.rejected:
+                    w2 = np.asarray(jax.device_get(pf.weights), np.float32).copy()
+                    w2[report.rejected] = 0.0
+                    alpha = (self._flat_alpha(n_accepted) if pf.alpha is None
+                             else pf.alpha)
+                    fused, _ = self._fuse_flat(
+                        pf.stage, jnp.asarray(w2), alpha, donate=True)
+            fused.block_until_ready()
+            rec = FusionRecord(
+                iteration=self.iteration,
+                n_contributions=pf.k,
+                n_accepted=n_accepted,
+                op=pf.op or self.fusion_op,
+                diff_norms=report.diff_norms if report else [],
+                wall_time=time.time() - pf.t0,
+                stage_s=pf.stage_s,
+            )
+            if self.keep_history:
+                self._snapshots.append(self._base)
+            self._publish_flat(fused)
+        rec.finalize_s = time.perf_counter() - t_fin
         pf.record = rec
         return rec
 
@@ -1141,7 +1177,7 @@ class Repository:
             raise ValueError(
                 f"staged buffer shape {buffer.data.shape} does not match "
                 f"the flat layout [K, {self._spec.size}]")
-        t0 = time.time()
+        t0, t_stage = time.time(), time.perf_counter()
         K = buffer.k
         w = self._cohort_weights(K, [])
         use_screen = self.screen if screen is None else bool(screen)
@@ -1152,6 +1188,7 @@ class Repository:
         pf = PendingFusion(
             stage=buffer if use_screen else None,
             fused=fused, sq=sq, weights=w, k=K, t0=t0,
+            stage_s=time.perf_counter() - t_stage,
             alpha=None if alpha is None else float(alpha),
             use_screen=None if screen is None else use_screen, op=op)
         if not wait:
@@ -1232,13 +1269,15 @@ class Repository:
             # the entries, so recovery skips them instead of re-applying).
             it, base, meta = self.iteration, self._base, self._render_meta()
             def task():
-                self._persist_base(it, base, meta)
+                with trace.span("repo.persist", iteration=rec.iteration):
+                    self._persist_base(it, base, meta)
                 faults.crash_point("repo.post_publish_pre_manifest")
                 with self._manifest_lock:
                     self._write_manifest()
             self._spill_futures.append(self._spill_pool.submit(task))
         else:
-            self._persist_base()
+            with trace.span("repo.persist", iteration=rec.iteration):
+                self._persist_base()
             faults.crash_point("repo.post_publish_pre_manifest")
             if self.spill or os.path.exists(self._manifest_path()):
                 # the second arm: a non-spill reopen that fused recovered
@@ -1502,6 +1541,8 @@ class Repository:
                     "op": r.op,
                     "diff_norms": [float(n) for n in r.diff_norms],
                     "wall_time": r.wall_time,
+                    "stage_s": r.stage_s,
+                    "finalize_s": r.finalize_s,
                 }
                 for r in self.history
             ],
@@ -1657,6 +1698,8 @@ class Repository:
                 op=r["op"],
                 diff_norms=[float(n) for n in r.get("diff_norms", [])],
                 wall_time=float(r.get("wall_time", 0.0)),
+                stage_s=float(r.get("stage_s", 0.0)),
+                finalize_s=float(r.get("finalize_s", 0.0)),
             )
             for r in meta.get("history", [])
         ]

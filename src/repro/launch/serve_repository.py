@@ -30,6 +30,10 @@ repository cross-process; ``--serve-batch`` enables the per-worker
 request queue (overload sheds explicitly instead of collapsing
 latency).  ``status()`` aggregates the whole worker namespace.
 
+``--trace-out PATH`` records the program's spans and counters
+(``repro.utils.trace``) and writes them to PATH as JSONL when the daemon
+stops; docs/observability.md names each one.
+
 ``REPRO_HOST_TUNING=1`` applies the opt-in host-throughput recipe
 (``repro/launch/host_tuning.py``): tcmalloc ``LD_PRELOAD`` when
 installed (the daemon re-execs itself once to pick it up, and pool
@@ -54,6 +58,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.serve.cold_service import AdmissionPolicy, ColdService
 from repro.serve.probes import ProbeSuite, RegressionGate
+from repro.utils import trace
 
 
 def build_service(args) -> ColdService:
@@ -200,7 +205,13 @@ def main(argv=None) -> int:
     p.add_argument("--idle-timeout", type=float, default=None,
                    help="stop after this many seconds without progress "
                         "(no admission, no publish, empty queue)")
+    p.add_argument("--trace-out", default=None, metavar="PATH",
+                   help="record the program's spans and counters and write "
+                        "them to PATH as JSONL on exit "
+                        "(docs/observability.md)")
     args = p.parse_args(argv)
+    if args.trace_out:
+        trace.enable()
 
     svc = build_service(args)
 
@@ -272,6 +283,9 @@ def main(argv=None) -> int:
           f"({st['novelty_rejected_total']} near-duplicates), "
           f"{st['rollbacks_total']} rollbacks "
           f"({st['quarantined_total']} submissions quarantined)", flush=True)
+    if args.trace_out:
+        trace.dump(args.trace_out)
+        print(f"[cold-service] trace written to {args.trace_out}", flush=True)
     return 0
 
 

@@ -82,7 +82,7 @@ from repro.checkpoint import io as ckpt
 from repro.core.repository import (NothingToFuse, Repository,
                                    RepositoryFamily, family_member_root)
 from repro.serve.probes import RegressionGate
-from repro.utils import faults
+from repro.utils import faults, trace
 from repro.utils.flat import (LANE, FamilyRouter, FlatSpec, ShardedFlatSpec,
                               delta_checksum, delta_encode,
                               delta_encode_sharded, row_checksum,
@@ -1338,7 +1338,10 @@ class ColdService:
         family on schedule, publish status, append metrics.  Returns the
         status dict it published."""
         self._cycle += 1
-        adm = self._admit()
+        # the base every span of this cycle is tagged with
+        it0 = self.repo.iteration
+        with trace.span("service.admit", iteration=it0):
+            adm = self._admit()
         gate_event = None
         published = []
         for lane in list(self._lanes.values()):
@@ -1383,17 +1386,18 @@ class ColdService:
                         lane.gate_baseline, lane.repo.flat_base_host()),
                         lane)
         if published:
-            self._gc_consumed()
-            for lane in published:
-                if (self.policy.compact_keep_bases is not None
-                        and not lane.repo.inflight):
-                    # compact only while quiescent: its flush() would
-                    # otherwise synchronously finalize the fuse dispatched
-                    # above and kill the wait=False overlap.  Deferred
-                    # compaction runs on the drain cycle that publishes
-                    # without redispatching.
-                    lane.repo.compact(
-                        keep_bases=self.policy.compact_keep_bases)
+            with trace.span("service.bookkeep", iteration=it0):
+                self._gc_consumed()
+                for lane in published:
+                    if (self.policy.compact_keep_bases is not None
+                            and not lane.repo.inflight):
+                        # compact only while quiescent: its flush() would
+                        # otherwise synchronously finalize the fuse
+                        # dispatched above and kill the wait=False overlap.
+                        # Deferred compaction runs on the drain cycle that
+                        # publishes without redispatching.
+                        lane.repo.compact(
+                            keep_bases=self.policy.compact_keep_bases)
         if (self._routing and self.policy.cross_fuse_every > 0
                 and self._cross_counter >= self.policy.cross_fuse_every
                 and len(self._lanes) >= 2
@@ -1403,10 +1407,11 @@ class ColdService:
             # persisted, so a crashed daemon neither skips nor repeats
             # the round it already took credit for)
             self._cross_fuse()
-        st = self.status(admitted=adm["admitted"],
-                         queue_depth=adm["queue_depth"])
-        ckpt.save_json_atomic(self._status_path, st)
-        self._emit_cycle_metrics(st, gate_event)
+        with trace.span("service.bookkeep", iteration=it0):
+            st = self.status(admitted=adm["admitted"],
+                             queue_depth=adm["queue_depth"])
+            ckpt.save_json_atomic(self._status_path, st)
+            self._emit_cycle_metrics(st, gate_event)
         return st
 
     def _cross_fuse(self) -> None:
@@ -1593,7 +1598,8 @@ class ColdService:
             "rollbacks_total": self._rollbacks,
             "last_gate": self._last_gate,
             "routing": self._routing,
-            "fuse_latency_s": last.wall_time if last else None,
+            "fuse_latency_s": (last.stage_s + last.finalize_s) if last
+                              else None,
             "last_fuse": None if last is None else {
                 "iteration": last.iteration,
                 "n_contributions": last.n_contributions,
