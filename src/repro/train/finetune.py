@@ -22,6 +22,7 @@ from repro.data.pipeline import batches
 from repro.models import encoder as E
 from repro.optim.optimizers import adamw, clip_by_global_norm, linear_decay_lr
 from repro.train.losses import accuracy, cls_loss
+from repro.utils import trace
 
 
 @functools.lru_cache(maxsize=None)
@@ -73,17 +74,35 @@ def finetune(
     num_classes = int(head["out"].shape[-1])
     opt, train_step, _ = _steps(cfg, num_classes, frozen_body, lr, lr_decay)
     trainable = {"head": head} if frozen_body else {"head": head, "body": body}
+    # a trained body reaches the step inside ``trainable``; passing it again
+    # would only add its leaves to every dispatch
+    static_body = body if frozen_body else None
     opt_state = opt.init(trainable)
     rng = np.random.default_rng(seed)
     losses, accs = [], []
     it = batches(x, y, batch_size, rng=rng, epochs=10_000)  # steps bound below
+    # The host runs one step ahead of the device: step n is dispatched while
+    # step n-1 runs, then the host waits for step n-1, so at most one step
+    # is queued behind the running one (one extra copy of the state, no
+    # more).  Losses and accuracies stay on the device until the end.
     for _ in range(steps):
         b = next(it)
-        trainable, opt_state, loss, acc = train_step(trainable, opt_state, body, b)
-        losses.append(float(loss))
-        accs.append(float(acc))
+        prev = losses[-1] if losses else None
+        if prev is not None:
+            trace.count("finetune.overlapped", not prev.is_ready())
+        trainable, opt_state, loss, acc = train_step(trainable, opt_state,
+                                                     static_body, b)
+        trace.count("finetune.steps", 1)
+        if prev is not None:
+            prev.block_until_ready()
+            trace.count("finetune.host_waits", 1)
+        losses.append(loss)
+        accs.append(acc)
+    losses, accs = jax.device_get((losses, accs))
+    trace.count("finetune.host_waits", 1)
     new_body = trainable.get("body", body)
-    return new_body, trainable["head"], {"loss": losses, "train_acc": accs}
+    return new_body, trainable["head"], {"loss": [float(v) for v in losses],
+                                         "train_acc": [float(v) for v in accs]}
 
 
 def compute_fisher(
