@@ -4,7 +4,9 @@ A driver builds the program's objects for a cell from the seed (``setup``),
 drives the timed path for a window (``window``), frees the program's state
 (``release``) and compares what the window produced with the reference
 (``check``).  Everything it sizes comes from the configuration and traffic
-files, so a new cell of an existing kind is data alone.
+files, so a new cell of an existing kind is data alone; the model's
+seeded weights come from the reference module the configuration file
+names (``reference``), so a new architecture is that file and a module.
 """
 from __future__ import annotations
 
@@ -48,18 +50,49 @@ def checks_from(limits: Mapping[str, float], readings: Mapping[str, float]
             if limits[k] is not None]
 
 
+def _replaced(obj, values: Mapping, where: str):
+    """``obj`` (a dataclass) with ``values`` replaced: a mapping given for a
+    field that holds a dataclass replaces that dataclass field by field.
+    Every value is checked after the replacement."""
+    fields = {f.name for f in dataclasses.fields(obj)}
+    unknown = sorted(set(values) - fields)
+    if unknown:
+        raise ValueError(f"{where}: no field {unknown} in "
+                         f"{type(obj).__name__}")
+    new = {}
+    for k, v in values.items():
+        cur = getattr(obj, k)
+        if dataclasses.is_dataclass(cur) and isinstance(v, Mapping):
+            v = _replaced(cur, v, f"{where}.{k}")
+        new[k] = v
+    out = dataclasses.replace(obj, **new)
+    for k, v in new.items():
+        if getattr(out, k) != v:
+            raise ValueError(f"{where}: {k} is {getattr(out, k)!r}, "
+                             f"the file says {values[k]!r}")
+    return out
+
+
 def program_config(conf: Mapping):
     """The program's configuration object for a configuration file: the
     object ``program`` names with the file's ``as_run`` values replaced,
-    checked field by field."""
+    checked field by field (a nested group, such as ``moe``, field by
+    field too)."""
     mod, attr = conf["program"].split(":")
     base = getattr(importlib.import_module(mod), attr)
-    cfg = dataclasses.replace(base, **conf["as_run"])
-    for k, v in conf["as_run"].items():
-        if getattr(cfg, k) != v:
-            raise ValueError(f"{conf['name']}: {k} is {getattr(cfg, k)!r}, "
-                             f"the file says {v!r}")
-    return cfg
+    return _replaced(base, conf["as_run"], conf["name"])
+
+
+def reference_module(conf: Mapping):
+    """The plain reference a configuration file names (``reference``, a
+    module under ``bench/reference/``): it gives the seeded body,
+    ``init_body(key, as_run)``, and its parameter count,
+    ``body_params(as_run)``."""
+    path = conf["reference"]
+    if not (path.startswith("bench/reference/") and path.endswith(".py")):
+        raise ValueError(f"{conf['name']}: reference {path!r} is not a "
+                         "module under bench/reference/")
+    return importlib.import_module(path[:-3].replace("/", "."))
 
 
 class Reservoir:

@@ -10,7 +10,8 @@ optimizer's first moment after step 1 (from which the clipped gradient is
 read back: its per-leaf norms and a seeded sample of its elements) and the
 parameters after the last recorded step.  After the window the float32
 reference (``reference.roberta``) takes the same steps from the same
-seeded weights on the same batches.
+seeded weights on the same batches (the reference module the
+configuration file names: ``reference.roberta`` for the encoders).
 
 Compared (PERF.md gives the readings each limit was set from): the first
 step's loss (``loss1_gap``; the later steps' losses swing by seed, since
@@ -30,11 +31,10 @@ from typing import Dict, List
 import jax
 import numpy as np
 
-from ..reference import roberta as R
 from ..reference.common import (change_norms, leaf_errors, leaf_gaps,
                                 leaf_norms, named_leaves, sample_index,
                                 seed_key, take_samples, worst_leaf_gap)
-from . import Window, checks_from, program_config
+from . import Window, checks_from, program_config, reference_module
 
 _PARAMS = 1
 
@@ -90,8 +90,10 @@ class StepRecorder:
 
 
 class Finetune:
-    def __init__(self, conf, traffic, seed: int, spans):
+    def __init__(self, conf, traffic, seed: int, spans, devices=None):
+        # one chip: the default device (a finetune cell asks for one)
         self.conf, self.t, self.seed, self.spans = conf, traffic, seed, spans
+        self.R = R = reference_module(conf)
         self.sz = R.Sizes.of(conf["as_run"], int(traffic["num_classes"]))
         o = traffic["optimizer"]
         self.hp = R.AdamW(float(traffic["lr"]), float(o["b1"]), float(o["b2"]),
@@ -104,7 +106,7 @@ class Finetune:
         self._refs = {}
 
     def _params0(self):
-        return R.init_params(self.key, sz=self.sz)
+        return self.R.init_params(self.key, sz=self.sz)
 
     def setup(self):
         from repro.core.contributor import Contributor
@@ -189,8 +191,8 @@ class Finetune:
             h = self.batch // 2
             batches = [{k: v[:h] for k, v in b.items()} for b in batches]
         p0 = self._params0()
-        losses, g, gs, p = R.train_steps(self.sz, self.hp, p0, batches,
-                                         self.index, prec)
+        losses, g, gs, p = self.R.train_steps(self.sz, self.hp, p0,
+                                              batches, self.index, prec)
         return {"losses": losses,
                 "grad": {k: float(v) for k, v in named_leaves(g).items()},
                 "grad_sample": named_leaves(gs),

@@ -7,7 +7,15 @@ of every element (standard deviation 1/32 of the leaf's RMS, or of 0.02
 where that is larger), so every round's cohort is distinct and close to the
 base, as finetuned models are.  The reference fuses the same rows
 (``reference.fuse``) after the window; it takes the previous base as its
-own fuse of the previous round's rows.
+own fuse of the previous round's rows.  The seeded base comes from the
+reference module the configuration file names.
+
+On more than one chip the in-process hub is ``Repository(mesh=)`` over a
+one-axis mesh of the cell's chips, and the base the benchmark makes is
+split over that mesh, each leaf along its last axis that the chip count
+divides.  Where the traffic says ``"arrive": "host"``, contributions are
+numpy pytrees in host memory, as a hub receives them off the network;
+otherwise they are made on, and stay on, the device.
 """
 from __future__ import annotations
 
@@ -25,8 +33,7 @@ import numpy as np
 
 from ..reference import fuse as ref
 from ..reference.common import flat, max_bf16_steps, named_leaves, seed_key
-from ..reference.roberta import Sizes, init_params
-from . import Reservoir, Window, checks_from
+from . import Reservoir, Window, checks_from, reference_module
 
 # key streams derived from the seed
 _PARAMS, _ROWS = 1, 2
@@ -58,8 +65,26 @@ def perturbed_row(base, key):
     return flat(_perturb(base, key))
 
 
-def make_trees(base, key, k: int):
-    return [perturbed(base, jax.random.fold_in(key, i)) for i in range(k)]
+def make_trees(base, key, k: int, host: bool = False):
+    """``k`` perturbed copies of ``base``, each made on the device; with
+    ``host``, each is read into host memory before the next is made."""
+    get = jax.device_get if host else (lambda t: t)
+    return [get(perturbed(base, jax.random.fold_in(key, i)))
+            for i in range(k)]
+
+
+def leaf_sharding(mesh, shape):
+    """A leaf split over the mesh's one axis along its last axis that the
+    axis's size divides (replicated where none does)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    (axis,), n = mesh.axis_names, mesh.devices.size
+    spec = [None] * len(shape)
+    for i in reversed(range(len(shape))):
+        if shape[i] % n == 0:
+            spec[i] = axis
+            break
+    return NamedSharding(mesh, P(*spec))
 
 
 def make_rows(base, key, k: int):
@@ -69,6 +94,16 @@ def make_rows(base, key, k: int):
 @jax.jit
 def _flat(tree):
     return flat(tree)
+
+
+def _sq_rel(got: np.ndarray, want: np.ndarray) -> float:
+    """The largest gap between two rounds' squared distances, relative to
+    the reference's (``inf`` where the counts differ or a gap is not
+    finite)."""
+    if got.shape != want.shape:
+        return float("inf")
+    rel = np.max(np.abs(got - want) / np.maximum(want, 1e-30))
+    return float(rel) if np.isfinite(rel) else float("inf")
 
 
 def read_npz_row(path: str, names: List[str], shapes) -> Optional[np.ndarray]:
@@ -90,18 +125,32 @@ class _FuseDriver:
     """What both kinds of round share: the seeded base, the sample of
     checked rounds and the comparison with the reference."""
 
-    def __init__(self, conf, traffic, seed: int, spans):
+    def __init__(self, conf, traffic, seed: int, spans, devices=None):
         self.conf, self.t, self.seed, self.spans = conf, traffic, seed, spans
         self.k = int(traffic["contributors"])
-        self.sz = Sizes.of(conf["as_run"], 2)
+        self.ref = reference_module(conf)
         self.key = seed_key(seed)
         self.sampled = Reservoir(int(traffic["check_rounds"]), seed)
         self.round = 0
         self.rounds: List[Dict] = []
+        self.mesh = None
+        if devices is not None and len(devices) > 1:
+            from repro.launch.mesh import make_mesh
+
+            self.mesh = make_mesh((len(devices),), ("model",),
+                                  devices=devices)
+
+    def _init(self, key):
+        return self.ref.init_body(key, self.conf["as_run"])
 
     def _base0(self):
-        return init_params(jax.random.fold_in(self.key, _PARAMS),
-                           sz=self.sz)["body"]
+        key = jax.random.fold_in(self.key, _PARAMS)
+        if self.mesh is None:
+            return self._init(key)
+        # made in place: one jitted call whose leaves come out split
+        out = jax.tree.map(lambda x: leaf_sharding(self.mesh, x.shape),
+                           jax.eval_shape(self._init, key))
+        return jax.jit(self._init, out_shardings=out)(key)
 
     # -- subclass API --------------------------------------------------
     def rows_of(self, r: int):
@@ -129,7 +178,13 @@ class _FuseDriver:
 
     def readings(self, mode: str = "program") -> Dict[str, float]:
         """The numbers compared for the sampled rounds.  ``mode="control"``
-        puts the reference computed in float8 in the program's place."""
+        puts the reference computed in float8 in the program's place.  On
+        a mesh, where the cohort is larger than a chip's memory as one
+        array, the reference reads the contribution pytrees in blocks
+        (``reference.fuse.fuse_tree``) rather than stacked ``[K, N]``
+        rows."""
+        if self.mesh is not None:
+            return self._readings_blocked(mode)
         base0 = self._base0()
         base0_flat = _flat(base0)
         names = list(named_leaves(base0))
@@ -158,14 +213,53 @@ class _FuseDriver:
             if self.npz_checked:
                 out["npz_steps"] = max(out["npz_steps"], float("inf") if npz is None
                                        else float(max_bf16_steps(jnp.asarray(npz), want)))
-            if gsq.shape != sq.shape:
-                out["sq_rel"] = float("inf")
-            else:
-                rel = np.max(np.abs(gsq - sq) / np.maximum(sq, 1e-30))
-                out["sq_rel"] = max(out["sq_rel"], float(rel) if np.isfinite(rel)
-                                    else float("inf"))
+            out["sq_rel"] = max(out["sq_rel"], _sq_rel(gsq, sq))
             want_acc = len(ref.screen(np.sqrt(sq), self.mad))
             out["screen_bad"] += float(accepted != want_acc) + float(n_contrib != self.k)
+        return out
+
+    def trees_of(self, r: int) -> List:
+        """Round ``r``'s contribution pytrees (the reference on a mesh)."""
+        raise NotImplementedError
+
+    def _round_blocked(self, base, r: int, prec: str):
+        """The reference's round ``r`` from ``base``, screened as
+        ``_screened`` screens: (fused pytree, sq [K], accepted indices)."""
+        rows = self.trees_of(r)
+        w = np.ones((self.k,), np.float32)
+        fused, sq = ref.fuse_tree(base, rows, w, 1.0, prec=prec)
+        keep = ref.screen(np.sqrt(sq), self.mad)
+        if len(keep) < self.k:
+            w[:] = 0.0
+            w[keep] = 1.0
+            fused, _ = ref.fuse_tree(base, rows, w, 1.0, prec=prec)
+        return fused, sq, keep
+
+    def _readings_blocked(self, mode: str) -> Dict[str, float]:
+        base0 = self._base0()
+        out = {"base_steps": 0.0, "sq_rel": 0.0, "screen_bad": 0.0}
+        for rec in self.sampled.sample():
+            r = rec["round"]
+            prev = (base0 if r == 0 else
+                    self._round_blocked(base0, r - 1, "f32")[0])
+            want, sq, keep = self._round_blocked(prev, r, "f32")
+            if mode == "control":
+                got, gsq, gkeep = self._round_blocked(prev, r, "fp8")
+                accepted, n_contrib = len(gkeep), self.k
+            else:
+                got = rec["base"]
+                gsq = np.square(np.asarray(rec["diff_norms"], np.float64))
+                accepted = rec["n_accepted"]
+                n_contrib = rec["n_contributions"]
+            del prev
+            g, w = jax.tree.flatten(got), jax.tree.flatten(want)
+            steps = (float("inf") if g[1] != w[1] else max(
+                float(max_bf16_steps(a, b)) for a, b in zip(g[0], w[0])))
+            del got, want, g, w
+            out["base_steps"] = max(out["base_steps"], steps)
+            out["sq_rel"] = max(out["sq_rel"], _sq_rel(gsq, sq))
+            out["screen_bad"] += (float(accepted != len(keep))
+                                  + float(n_contrib != self.k))
         return out
 
     def check(self, mode: str = "program"):
@@ -176,10 +270,15 @@ class _FuseDriver:
 
 
 class InProcess(_FuseDriver):
-    """``Repository(base, screen=True)`` with no root: each round uploads
-    one of two cohorts of ``contributors`` seeded pytrees, made once in
-    set-up, in turn (so consecutive cohorts differ and each row is as far
-    from the previous base as the others), and calls ``fuse_pending()``."""
+    """``Repository(base, screen=True)`` with no root (with ``mesh=`` over
+    the cell's chips where it has more than one).  Set-up makes a pool of
+    ``pool`` seeded pytrees (default two cohorts, ``2 * contributors``),
+    in host memory where the traffic's ``arrive`` is ``host``;
+    round ``r`` uploads the ``contributors`` trees from index
+    ``r * stride`` on, modulo the pool (default stride ``contributors``:
+    the two cohorts in turn), and calls ``fuse_pending()``.  So
+    consecutive cohorts differ, and each row is as far from the previous
+    base as the others."""
 
     npz_checked = False
 
@@ -187,21 +286,43 @@ class InProcess(_FuseDriver):
         from repro.core.repository import Repository
 
         self.mad = 5.0
+        self.pool_size = int(self.t.get("pool", 2 * self.k))
+        self.stride = int(self.t.get("stride", self.k))
         base0 = self._base0()
         self.pool = make_trees(base0, jax.random.fold_in(self.key, _ROWS),
-                               2 * self.k)
+                               self.pool_size,
+                               host=self.t.get("arrive") == "host")
         jax.block_until_ready(self.pool)
-        self.repo = Repository(base0, screen=True, mad_threshold=self.mad)
+        self.repo = Repository(base0, screen=True, mad_threshold=self.mad,
+                               mesh=self.mesh)
         del base0
         for _ in range(int(self.t["warmup_rounds"])):
             self._round(timed=False)
-        jax.block_until_ready(self.repo.download())
+        base = self.repo.download()
+        if self.mesh is not None:
+            # made and run once here, so that keeping a sampled base in
+            # the window compiles nothing
+            self._split = jax.jit(lambda t: t, out_shardings=jax.tree.map(
+                lambda x: leaf_sharding(self.mesh, x.shape), base))
+            base = self._split(base)
+        jax.block_until_ready(base)
+
+    def _kept(self, tree):
+        """A sampled base as the benchmark keeps it for the comparison
+        after the window.  On a mesh the published base is whole on every
+        chip, so it is split like the trees, on the chips: a
+        ``jax.device_put`` to the split layout would read it to the host."""
+        return tree if self.mesh is None else self._split(tree)
 
     def members(self, r: int) -> List[int]:
-        return list(range((r % 2) * self.k, (r % 2 + 1) * self.k))
+        return [(r * self.stride + i) % self.pool_size
+                for i in range(self.k)]
 
     def rows_of(self, r: int):
         return jnp.stack([_flat(self.pool[p]) for p in self.members(r)])
+
+    def trees_of(self, r: int) -> List:
+        return [self.pool[p] for p in self.members(r)]
 
     def _round(self, timed: bool = True):
         r = self.round
@@ -215,10 +336,13 @@ class InProcess(_FuseDriver):
         self.round += 1
         if timed:
             self.rounds.append({"s": dt})
-            self.sampled.offer({"round": r, "base": self.repo.download(),
-                                "diff_norms": list(rec.diff_norms),
-                                "n_accepted": rec.n_accepted,
-                                "n_contributions": rec.n_contributions})
+            item = {"round": r, "base": self.repo.download(),
+                    "diff_norms": list(rec.diff_norms),
+                    "n_accepted": rec.n_accepted,
+                    "n_contributions": rec.n_contributions}
+            self.sampled.offer(item)
+            if any(x is item for x in self.sampled.items):
+                item["base"] = self._kept(item["base"])
         return dt
 
     def window(self, seconds: float) -> Window:
@@ -228,6 +352,8 @@ class InProcess(_FuseDriver):
         # every round's work has ended, the last publish included
         jax.block_until_ready(self.repo.download())
         total = time.perf_counter() - t0
+        last = self.sampled.last
+        last["base"] = self._kept(last["base"])
         times = [x["s"] for x in self.rounds]
         times[-1] += total - sum(times)
         n = len(times)
@@ -237,7 +363,8 @@ class InProcess(_FuseDriver):
                     "fuse_round_p95_ms": 1e3 * float(np.percentile(times, 95))},
             counters={"rounds": n, "window_s": total, "k": self.k,
                       "n": int(sum(np.prod(x.shape) for x in
-                                   jax.tree.leaves(self.pool[0])))})
+                                   jax.tree.leaves(self.pool[0]))),
+                      "chips": 1 if self.mesh is None else self.mesh.size})
 
     def release(self):
         self.repo = None

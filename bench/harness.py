@@ -5,6 +5,9 @@ A cell names a configuration (``configs/<config>.json``) and a traffic mix
 end-to-end metrics the cell reports are those of ``end_to_end`` whose
 ``workloads`` list it (or that have no list); its per-layer metrics are the
 ``per_layer`` entries that list it, each read by ``metrics/<name>.py``.
+The driver gets the cell's chips; in a traced run the program's own spans
+and counters (``repro.utils.trace``) are recorded too, and handed to the
+readers beside the benchmark's.
 """
 from __future__ import annotations
 
@@ -87,13 +90,46 @@ def metric_reader(name: str):
 class Context:
     """What a per-layer reader may read: the cell's sizes and traffic, the
     benchmark's spans of the traced window, the reduced trace, the window's
-    counters, and the chip's peaks."""
+    counters, the chip's peaks, and the program's spans that lie in the
+    window with its counters' change over the window."""
     cfg: Mapping
     traffic: Mapping
     spans: tracing.Spans
     trace: Optional[Dict]
     counters: Mapping
     peaks: Optional[Mapping]
+    program: tracing.Spans = dataclasses.field(default_factory=tracing.Spans)
+    program_counters: Mapping = dataclasses.field(default_factory=dict)
+
+
+class ProgramTrace:
+    """The program's spans and counters (``repro.utils.trace``) over one
+    window: recording is switched on at ``start`` and back to what it was
+    at ``stop``.  ``spans(lo, hi)`` are those that lie within [lo, hi] on
+    the host clock, ``counters`` each counter's change."""
+
+    def __init__(self):
+        from repro.utils import trace
+
+        self.trace, self.was = trace, trace.enabled()
+        self.c0: Dict[str, int] = {}
+        self.counters: Dict[str, int] = {}
+
+    def start(self) -> None:
+        self.trace.enable()
+        self.c0 = self.trace.counters()
+
+    def stop(self) -> None:
+        c1 = self.trace.counters()
+        self.counters = {k: v - self.c0.get(k, 0) for k, v in c1.items()}
+        if not self.was:
+            self.trace.disable()
+
+    def spans(self, lo: float, hi: float) -> tracing.Spans:
+        out = tracing.Spans()
+        out.records = [(s.name, s.t0, s.t1) for s in self.trace.records()
+                       if lo <= s.t0 and s.t1 <= hi]
+        return out
 
 
 class CompileCount:
@@ -132,8 +168,10 @@ def run(cell: Mapping, seed: int, seconds: float, trace: bool, *,
 
     traffic = cell["traffic_file"]
     spans = tracing.Spans(annotate=trace)
-    drv = driver_for(traffic["kind"])(cell["config_file"], traffic, seed, spans)
+    drv = driver_for(traffic["kind"])(cell["config_file"], traffic, seed,
+                                      spans, devices)
     compiles = CompileCount()
+    program = ProgramTrace() if trace else None
     logdir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     try:
         drv.setup()
@@ -146,6 +184,7 @@ def run(cell: Mapping, seed: int, seconds: float, trace: bool, *,
             opts.host_tracer_level = 1  # the benchmark's spans, not the runtime's
             jax.profiler.start_trace(logdir, create_perfetto_trace=True,
                                      profiler_options=opts)
+            program.start()
         compiles.active = True
         try:
             with spans.span(tracing.WINDOW):
@@ -153,6 +192,7 @@ def run(cell: Mapping, seed: int, seconds: float, trace: bool, *,
         finally:
             compiles.active = False
             if trace:
+                program.stop()
                 jax.profiler.stop_trace()
         if compiles.n:
             print(f"[bench] warning: {compiles.n} compilations or cache loads "
@@ -166,9 +206,12 @@ def run(cell: Mapping, seed: int, seconds: float, trace: bool, *,
             extra["look"] = drv.look()
         red = None
         if trace:
+            lo, hi = spans.window()
+            prog = program.spans(lo, hi)
             path = tracing.find_trace(logdir)
-            red = tracing.reduce_trace(tracing.load_events(path),
-                                       {n for n, _, _ in spans.records})
+            red = tracing.reduce_trace(
+                tracing.load_events(path),
+                {n for n, _, _ in spans.records + prog.records})
     finally:
         drv.close()
         if logdir:
@@ -177,8 +220,8 @@ def run(cell: Mapping, seed: int, seconds: float, trace: bool, *,
     metrics: Dict[str, Dict] = {}
     if trace:
         ctx = Context(cell["config_file"]["as_run"], traffic,
-                      spans.inside(), red,
-                      win.counters, peaks)
+                      spans.inside(), red, win.counters, peaks,
+                      program=prog, program_counters=program.counters)
         for m in cell["per_layer"]:
             v = metric_reader(m["name"])(ctx)
             if v is not None:

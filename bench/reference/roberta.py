@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import counts
 from .common import HIGHEST, leaf_norms, quantize, take_samples
 
 
@@ -81,6 +82,18 @@ def init_params(key, *, sz: Sizes, dtype=jnp.bfloat16):
     head = {"dense": dense(d, d), "out": dense(d, sz.num_classes),
             "bias": jnp.zeros((sz.num_classes,), dtype)}
     return {"body": body, "head": head}
+
+
+def init_body(key, as_run):
+    """The seeded shared body (what a contribution carries) of a
+    configuration file's ``as_run``: ``init_params``'s ``body``, so the same
+    seed gives the same bytes as the finetune cells' starting weights."""
+    return init_params(key, sz=Sizes.of(as_run, 2))["body"]
+
+
+def body_params(as_run) -> int:
+    """Parameters of that body (``counts.body_params``)."""
+    return counts.body_params(as_run)
 
 
 def _mm(a, b, prec):

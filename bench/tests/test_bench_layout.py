@@ -6,9 +6,11 @@ import shutil
 import subprocess
 import sys
 
+import jax
 import pytest
 
-from bench import counts, harness
+from bench import harness
+from bench.drivers import program_config, reference_module
 
 from .conftest import ROOT
 
@@ -43,14 +45,63 @@ def test_config_file(conf):
     assert conf["file"].startswith("bench/configs/")
     assert data["name"] == conf["name"] and data["source"] == conf["source"]
     assert data["reduced"] == conf["reduced"]
-    assert counts.body_params(data["as_run"]) == data["body_parameters"]
+    # the count comes from the reference module the file names
+    ref = reference_module(data)
+    assert ref.body_params(data["as_run"]) == data["body_parameters"]
     assert any(w["config"] == conf["name"] for w in BM["workloads"])
+
+
+def _leaves(tree):
+    return [(jax.tree_util.keystr(p), x.shape, x.dtype)
+            for p, x in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+def _conf(name):
+    return harness.load_json(os.path.join(ROOT, "bench", "configs",
+                                          name + ".json"))
+
+
+def test_granite_reference_body_is_the_programs_layout():
+    """The reference's seeded body has the program's leaves (``init_lm``
+    of the configuration as run): names, shapes and dtypes, at the
+    published widths."""
+    from repro.models.transformer import init_lm
+
+    conf = _conf("granite-moe-1b-a400m")
+    cfg = program_config(conf)
+    key = jax.ShapeDtypeStruct((2,), "uint32")
+    prog = jax.eval_shape(lambda k: init_lm(cfg, k), key)
+    body = jax.eval_shape(
+        lambda k: reference_module(conf).init_body(k, conf["as_run"]), key)
+    assert _leaves(body) == _leaves(prog)
+    assert len(_leaves(body)) == 12
+    assert sum(x.size for x in jax.tree.leaves(prog)) == conf["body_parameters"]
+    # depth alone is cut: at the published 24 layers the count is the
+    # published one
+    whole = dict(conf["as_run"], num_layers=24)
+    n = reference_module(conf).body_params(whole)
+    assert n == conf["body_parameters_published"] == 1_334_628_352
+
+
+def test_program_config_replaces_a_nested_group_field_by_field():
+    conf = _conf("granite-moe-1b-a400m")
+    cfg = program_config(conf)
+    # a dataclass still, with the fields the file leaves out kept
+    assert cfg.moe.num_experts == 32 and cfg.moe.experts_per_token == 8
+    assert cfg.moe.routing == "gshard"
+    assert cfg.num_layers == conf["as_run"]["num_layers"]
+    bad = dict(conf, as_run=dict(conf["as_run"], moe={"num_expert": 32}))
+    with pytest.raises(ValueError, match="num_expert"):
+        program_config(bad)
+    bad = dict(conf, as_run=dict(conf["as_run"], layers=3))
+    with pytest.raises(ValueError, match="layers"):
+        program_config(bad)
 
 
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_resolves(name):
     cell = harness.resolve(BM, name)
-    assert cell["chips"] == 1
+    assert cell["chips"] in (1, 4)
     kinds = {"queue", "inproc", "finetune"}
     assert cell["traffic_file"]["kind"] in kinds
     e2e = {m["name"] for m in cell["end_to_end"]}
@@ -63,6 +114,11 @@ def test_cell_resolves(name):
         assert m["moves"] in e2e
     pairs = [(w["config"], w["traffic"]) for w in BM["workloads"]]
     assert len(pairs) == len(set(pairs))
+
+
+def test_four_chip_cells_are_within_the_cap():
+    four = [w["name"] for w in BM["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(BM["workloads"]) // 2), four
 
 
 def test_unknown_device_kind_is_an_error():

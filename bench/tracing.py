@@ -1,7 +1,8 @@
 """The benchmark's host spans and its reduction of a profiler trace.
 
 ``Spans`` times the benchmark's own calls into the program on the host
-clock.  In a traced run each span is also a ``jax.profiler.TraceAnnotation``
+clock (and holds the program's own spans, read from ``repro.utils.trace``,
+in a traced run).  In a traced run each span is also a ``jax.profiler.TraceAnnotation``
 of the same name, so the trace carries it on the device's clock and an idle
 gap on the device can be named by the span that was open on the host.
 
@@ -57,9 +58,13 @@ class Spans:
     def durations(self, name: str) -> List[float]:
         return [b - a for n, a, b in self.records if n == name]
 
+    def window(self, name: str = WINDOW) -> Tuple[float, float]:
+        """Start and end of the last span called ``name``."""
+        return next((a, b) for n, a, b in reversed(self.records) if n == name)
+
     def inside(self, name: str = WINDOW) -> "Spans":
         """The spans that lie within the last span called ``name``."""
-        lo, hi = next((a, b) for n, a, b in reversed(self.records) if n == name)
+        lo, hi = self.window(name)
         out = Spans(self.annotate)
         out.records = [r for r in self.records
                        if r[0] != name and lo <= r[1] and r[2] <= hi]
@@ -88,6 +93,12 @@ def union(intervals: Iterable[Tuple[float, float]]) -> List[Tuple[float, float]]
         else:
             out.append([a, b])
     return [(a, b) for a, b in out]
+
+
+def _base_name(name: str) -> str:
+    """A host annotation with attributes carries them after a ``#``
+    (``repo.finalize#iteration=3#``)."""
+    return name.split("#", 1)[0]
 
 
 def _strip_suffix(name: str) -> str:
@@ -143,8 +154,8 @@ def reduce_trace(events: Sequence[dict], span_names: Iterable[str] = ()) -> Dict
             elif threads.get((pid, e.get("tid")), "").startswith(_SUMMARY_LINES):
                 continue
             ops[pid].append((t0, t1, e.get("name", "")))
-        elif e.get("name") in span_names:
-            spans[e["name"]].append((t0, t1))
+        elif _base_name(e.get("name", "")) in span_names:
+            spans[_base_name(e["name"])].append((t0, t1))
     if spans.get(WINDOW):
         lo, hi = spans[WINDOW][0][0], spans[WINDOW][-1][1]
     elif ops:
